@@ -26,7 +26,6 @@ from repro.configs.common import named, sds
 from repro.configs.lm_common import build_lm_cell
 from repro.distrib import masked_psum_lookup
 from repro.launch.hlo_cost import analyze_hlo
-from repro.compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.optim.optimizers import ScaleByAdamState
 from repro.optim.sparse import (init_sparse_table_state, sparse_adamw_update,
@@ -37,7 +36,7 @@ PEAK_FLOPS, HBM_BW, LINK_BW = 197e12, 819e9, 50e9
 
 def measure(name, fn, args, in_sh, out_sh, donate=(), mesh=None):
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                            donate_argnums=donate).lower(*args).compile()
     walk = analyze_hlo(compiled.as_text())
@@ -210,7 +209,7 @@ def run_clax(mesh):
             mesh=mesh)
 
     B, K = 65536, clax_baidu.POSITIONS
-    model = clax_baidu._make_model("ubm")
+    model = clax_baidu.make_model("ubm")
     attr = model.parts["attraction"]
     R = attr.table_rows
     dp = ("data",) if "pod" not in mesh.axis_names else ("pod", "data")
@@ -407,7 +406,7 @@ def run_deepfm_v5(mesh):
 def run_clax_v3(mesh):
     from repro.configs import clax_baidu
     cell = clax_baidu.build_cell("train_batch", mesh, kind="ubm")
-    pspecs, params = clax_baidu._param_specs(clax_baidu._make_model("ubm"))
+    pspecs, params = clax_baidu._param_specs(clax_baidu.make_model("ubm"))
     dp = ("data",) if "pod" not in mesh.axis_names else ("pod", "data")
     pspecs["attraction"]["table"] = P(dp + ("model",), None)
     optimizer = optim_lib.adamw(3e-3, weight_decay=1e-4)

@@ -15,7 +15,7 @@ from jax.sharding import PartitionSpec as P
 from repro import optim as optim_lib
 from repro.configs.common import Cell, dp_axes, named, sds
 from repro.core import (EmbeddingParameterConfig, Compression,
-                        DynamicBayesianNetwork, UserBrowsingModel)
+                        MODEL_REGISTRY)
 
 POSITIONS = 10
 # 2^31 ids hashed 10x, rounded to divide the model axis (16) and 512 devices.
@@ -27,18 +27,15 @@ SHAPES = {
 }
 
 
-def _make_model(kind: str):
+def make_model(kind: str):
+    """A ``MODEL_REGISTRY`` click model (e.g. "ubm", "dbn", "dctr") whose
+    every embedding table is the hashed 2^31-id table of this workload."""
     attraction = EmbeddingParameterConfig(
         parameters=1 << 31, compression=Compression.HASH,
         compression_ratio=10.0, baseline_correction=True,
         init_logit=-2.0)
-    if kind == "ubm":
-        model = UserBrowsingModel(positions=POSITIONS, attraction=attraction)
-    else:
-        model = DynamicBayesianNetwork(positions=POSITIONS,
-                                       attraction=attraction,
-                                       satisfaction=attraction)
-    return model
+    return MODEL_REGISTRY[kind](positions=POSITIONS, attraction=attraction,
+                                satisfaction=attraction)
 
 
 def _param_specs(model):
@@ -57,7 +54,7 @@ def build_cell(shape: str, mesh, kind: str = "ubm") -> Cell:
     info = SHAPES[shape]
     B = info["batch"]
     dp = dp_axes(mesh)
-    model = _make_model(kind)
+    model = make_model(kind)
     pspecs, params = _param_specs(model)
 
     batch = {

@@ -34,8 +34,9 @@ the codec choice is deterministic in those rows, the parallel store is
 manifest alike (metadata records the actual ``ingest_workers``) — pinned
 in tests/test_ingest.py.
 
-Workers are ``spawn`` processes that import only the numpy side of
-``repro.data`` (no jax), so they start in well under a second.
+Workers are ``spawn`` processes. Importing ``repro.data`` imports jax, but
+a worker only runs numpy and never initializes a JAX backend, so it never
+claims an accelerator that its parent process holds.
 """
 from __future__ import annotations
 

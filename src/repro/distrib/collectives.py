@@ -20,7 +20,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distrib.shardings import DATA_AXES, MODEL_AXIS

@@ -33,11 +33,11 @@ from repro.kernels import dispatch as _dispatch
 from repro.kernels import ref as _ref
 from repro.kernels.dcn_cross import dcn_cross_pallas
 from repro.kernels.embedding_bag import embedding_bag_pallas
-from repro.kernels.examination_nll import (examination_nll_pallas,
+from repro.kernels.examination_nll import (examination_nll_partials,
                                            examination_nll_xla)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fm_interaction import fm_interaction_pallas
-from repro.kernels.session_nll import session_nll_pallas
+from repro.kernels.session_nll import session_nll_partials
 
 # Re-exported so callers can flip impls without importing the registry module.
 override_impl = _dispatch.override_impl
@@ -47,6 +47,27 @@ resolve_impl = _dispatch.resolve_impl
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _nll_pallas(partials, *args):
+    """Masked-mean NLL from a kernel's per-block (sums, counts).
+
+    Under a mesh (``jax.set_mesh``, as the data-parallel engine traces), a
+    Mosaic kernel cannot be partitioned by XLA: it runs per data shard in a
+    ``shard_map`` and only the (G, 1, 1) partials cross devices.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distrib.shardings import DATA_AXES
+
+    fn = functools.partial(partials, interpret=_interpret())
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and DATA_AXES(mesh):
+        data = DATA_AXES(mesh)
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=P(data, None),
+                           out_specs=P(data), check_vma=False)
+    sums, counts = fn(*args)
+    return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -107,8 +128,7 @@ _dispatch.register("embedding_bag", "ref", _ref.embedding_bag_ref)
 _dispatch.register("embedding_bag", "xla", _embedding_bag_xla)
 
 _dispatch.register("session_nll", "pallas",
-                   lambda x, c, m: session_nll_pallas(
-                       x, c, m, interpret=_interpret()))
+                   functools.partial(_nll_pallas, session_nll_partials))
 _dispatch.register("session_nll", "ref", _ref.session_nll_ref)
 _dispatch.register("session_nll", "xla", _session_nll_xla)
 
@@ -131,8 +151,7 @@ _dispatch.register("flash_attention", "ref", _ref.flash_attention_ref)
 _dispatch.register("flash_attention", "xla", _flash_attention_xla)
 
 _dispatch.register("examination_nll", "pallas",
-                   lambda *a: examination_nll_pallas(
-                       *a, interpret=_interpret()))
+                   functools.partial(_nll_pallas, examination_nll_partials))
 _dispatch.register("examination_nll", "ref", _ref.examination_nll_ref)
 _dispatch.register("examination_nll", "xla", examination_nll_xla)
 

@@ -22,7 +22,6 @@ import jax
 
 from repro.configs import registry
 from repro.launch.hlo_cost import analyze_hlo
-from repro.compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -96,7 +95,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
     mesh = make_production_mesh(multi_pod=multi_pod)
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = registry.build_cell(arch, shape, mesh)
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                          out_shardings=cell.out_shardings,

@@ -5,7 +5,14 @@ device state.
 """
 from __future__ import annotations
 
-from repro.compat import make_auto_mesh
+import jax
+
+
+def make_auto_mesh(axis_shapes, axis_names):
+    """jax.make_mesh with every axis of Auto type (sharding left to XLA)."""
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
+
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +31,5 @@ def make_data_parallel_mesh(n_devices: int | None = None):
     'data', params (``clax_param_rule``) land on the size-1 'model' axis —
     i.e. replicated across the data ranks. The shape every single-host
     ``--data-parallel`` training run uses."""
-    import jax
-
     n = n_devices or jax.local_device_count()
     return make_auto_mesh((n, 1), ("data", "model"))

@@ -17,16 +17,19 @@ failures (slow/failing model, poisoned requests, mid-flight SIGTERM);
 counters are bit-deterministic. Telemetry (per-request latency metrics,
 dispatch spans, breaker events, final ``serve_summary``) rides the
 standard Recorder sinks.
+
+The run exits non-zero when a model raised or a request was answered off
+the ``primary`` tier, unless a fault flag or ``--force-tier`` asked for it.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import sys
 
 import jax
 
 from repro.core import Compression, EmbeddingParameterConfig, MODEL_REGISTRY
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import (ModelRegistry, ServeEngine, ServiceModel,
                          VirtualClock, WallClock, poisson_trace)
 from repro.testing import PoisonTrace, ServeKillSwitch, SlowModel
@@ -89,6 +92,16 @@ def build_faults(args):
     return faults
 
 
+def unasked_degradation(args, engine, summary) -> int:
+    """Model errors plus requests answered off ``primary``; 0 when the run
+    asked for faults or a tier. Every request a model error sheds was
+    preceded by that error, so it is counted too."""
+    if (args.fault_slow_model or args.fault_poison_every
+            or args.fault_sigterm_at is not None or args.force_tier):
+        return 0
+    return engine.stats["serve.model_errors"] + summary["degraded"]
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="dbn", choices=sorted(MODEL_REGISTRY))
@@ -135,6 +148,7 @@ def main(argv=None):
                     help="write the final summary (plus health and "
                          "counters) as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro import obs
 
@@ -186,8 +200,13 @@ def main(argv=None):
           f"hit={summary['deadline_hit_rate']:.3f} "
           f"p50={summary['p50_ms'] if summary['p50_ms'] is None else round(summary['p50_ms'], 2)}ms "
           f"p99={summary['p99_ms'] if summary['p99_ms'] is None else round(summary['p99_ms'], 2)}ms")
+    failures = unasked_degradation(args, engine, summary)
+    if failures:
+        raise SystemExit(f"[serve] {engine.stats['serve.model_errors']} "
+                         f"model errors and {summary['degraded']} degraded "
+                         "requests with no fault or tier asked for")
     return summary
 
 
 if __name__ == "__main__":
-    sys.exit(0 if main() is not None else 1)
+    main()

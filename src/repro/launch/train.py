@@ -56,6 +56,7 @@ from repro.core import (Compression, EmbeddingParameterConfig, MODEL_REGISTRY)
 from repro.data import (ClickLogLoader, SessionStore, StreamingClickLogLoader,
                         SyntheticConfig, generate_click_log, ingest_synthetic,
                         split_sessions)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import Trainer
 
 
@@ -276,6 +277,7 @@ def main():
                  "lazy-AdamW lr is a static hyperparameter shared by all "
                  "replicas); per-seed sweeps (--replica-seeds) are fine")
 
+    enable_compile_cache()
     if args.kernel_impl:
         # Before anything traces: the dispatch registry resolves at trace
         # time, so the override must exist before the engine compiles.

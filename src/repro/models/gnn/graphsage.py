@@ -21,7 +21,7 @@ from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import optim as optim_lib

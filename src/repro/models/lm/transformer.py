@@ -32,7 +32,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import optim as optim_lib
@@ -247,15 +247,9 @@ def param_specs(cfg: LMConfig, mesh) -> Any:
 
 def _wsc(x: jax.Array, spec: P) -> jax.Array:
     """with_sharding_constraint that no-ops outside a mesh context."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-    except AttributeError:  # jax < 0.5: context mesh lives in thread_resources
-        from jax.interpreters import pxla
-
-        if pxla.thread_resources.env.physical_mesh.empty:
-            return x
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
+        return x
     return jax.lax.with_sharding_constraint(x, spec)
 
 

@@ -7,10 +7,9 @@ for instead of hand-timing around warmup/compile. The trace lands in
 ``log_dir`` in TensorBoard/Perfetto format (``jax.profiler.start_trace``).
 
 The window piggybacks on the train loop's existing chunk boundaries: it
-adds zero host syncs and zero dispatches of its own. Profiler availability
-is probed lazily — when the runtime has no profiler support, the window
-degrades to emitting its open/close telemetry events only (never crashes
-the run).
+adds zero host syncs and zero dispatches of its own. A trace that was asked
+for and cannot start or stop raises: a run that silently lost its profile
+would be read as one that had none to take.
 """
 from __future__ import annotations
 
@@ -61,17 +60,10 @@ class ProfileWindow:
     def before_chunk(self, next_step: int) -> None:
         if self.done or self.active or next_step < self.start_step:
             return
-        self.active = True
-        try:
-            import jax.profiler
+        import jax.profiler
 
-            jax.profiler.start_trace(self.log_dir)
-            started = True
-        except Exception as e:  # no profiler in this runtime — degrade
-            started = False
-            self._rec().event("profile_unavailable", step=next_step,
-                              error=repr(e))
-        self._started = started
+        jax.profiler.start_trace(self.log_dir)
+        self.active = True
         self._rec().event("profile_start", step=next_step,
                           log_dir=self.log_dir)
 
@@ -87,15 +79,9 @@ class ProfileWindow:
                        else reached_step)
 
     def _stop(self, step: int) -> None:
+        import jax.profiler
+
         self.active = False
         self.done = True
-        if getattr(self, "_started", False):
-            try:
-                import jax.profiler
-
-                jax.profiler.stop_trace()
-            except Exception as e:
-                self._rec().event("profile_stop_failed", step=step,
-                                  error=repr(e))
-                return
+        jax.profiler.stop_trace()
         self._rec().event("profile_stop", step=step, log_dir=self.log_dir)

@@ -70,6 +70,7 @@ them one at a time. The engine replaces it with a chunked execution core:
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -469,13 +470,29 @@ class TrainEngine:
         (or omitted) mask takes the select-free fast path; a partial mask is
         a traced argument, so flipping further replicas off never retraces.
         """
-        if self.replicas is None:
-            if active is not None:
-                raise ValueError("active mask requires TrainEngine(replicas=R)")
-            return self._step(params, opt_state, chunk)
-        if active is None or bool(np.asarray(active).all()):
-            return self._step(params, opt_state, chunk)
-        return self._step_masked(params, opt_state, chunk, jnp.asarray(active))
+        with self._mesh_context():
+            if self.replicas is None:
+                if active is not None:
+                    raise ValueError(
+                        "active mask requires TrainEngine(replicas=R)")
+                return self._step(params, opt_state, chunk)
+            if active is None or bool(np.asarray(active).all()):
+                return self._step(params, opt_state, chunk)
+            return self._step_masked(params, opt_state, chunk,
+                                     jnp.asarray(active))
+
+    def _mesh_context(self):
+        """The engine's mesh as the context mesh while tracing, so kernels
+        that XLA cannot partition run per data shard (kernels/ops.py)."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
+    def compiled(self, params, opt_state, chunk):
+        """The compiled chunk program for these arguments' shapes (the one
+        :meth:`step` runs; a cache hit once ``step`` has run them)."""
+        with self._mesh_context():
+            return self._step.lower(params, opt_state, chunk).compile()
 
     def roofline(self, params, opt_state, chunk) -> Dict[str, Any]:
         """Static per-dispatch cost of the compiled chunk step: lower +
@@ -486,7 +503,7 @@ class TrainEngine:
         emits it once, as a ``roofline`` telemetry event)."""
         from repro.launch.hlo_cost import analyze_hlo
 
-        hlo = self._step.lower(params, opt_state, chunk).compile().as_text()
+        hlo = self.compiled(params, opt_state, chunk).as_text()
         cost = analyze_hlo(hlo)
         n = jax.tree_util.tree_leaves(chunk)[0].shape[0]
         cost["chunk_batches"] = int(n)

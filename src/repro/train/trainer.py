@@ -135,6 +135,12 @@ class Trainer:
         # model being evaluated every epoch survives a >4-model sweep.
         self._eval_cache: Dict[Any, tuple] = {}
 
+    @property
+    def final_state(self) -> TrainState:
+        """The state the last :meth:`train` call ended with (its
+        :attr:`engine` is the one that ran it)."""
+        return self._final_state
+
     def _rec(self):
         """The recorder events go to: the pinned one, else the global."""
         return self.recorder if self.recorder is not None else get_recorder()
@@ -202,7 +208,7 @@ class Trainer:
     def train(self, model, train_loader, val_loader=None,
               state: Optional[TrainState] = None,
               resume: bool = False) -> List[Dict[str, float]]:
-        engine = self._make_engine(model)
+        engine = self.engine = self._make_engine(model)
         R = self.replicas
         if state is None:
             if R is None:

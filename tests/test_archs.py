@@ -158,7 +158,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_auto_mesh, set_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.models.lm import LMConfig, init_params, forward
 
 # capacity_factor >= n_experts => lossless routing => shard_map == dense oracle
@@ -171,7 +171,7 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0, 64)
 dense = forward(cfg, params, toks, mesh=None)
 
 mesh = make_auto_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     sharded = jax.jit(lambda p, t: forward(cfg, p, t, mesh=mesh))(params, toks)
 err = float(jnp.max(jnp.abs(dense.astype(jnp.float32) - sharded.astype(jnp.float32))))
 assert err < 2e-2, err
@@ -198,7 +198,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, dataclasses
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_auto_mesh, set_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.models.lm import LMConfig, init_params, init_cache, make_decode_step, forward
 
 cfg0 = LMConfig(name="m", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
@@ -209,7 +209,7 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0, 64)
 nxt = jax.random.randint(jax.random.PRNGKey(3), (4, 1), 0, 64)
 ref = forward(cfg0, params, jnp.concatenate([toks, nxt], 1))[:, -1]
 mesh = make_auto_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     cache = init_cache(cfg0, batch=4, max_seq=16)
     dec_dense = make_decode_step(cfg0, mesh=mesh)
     for i in range(8):
@@ -244,7 +244,7 @@ DST_PARTITIONED_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, dataclasses
-from repro.compat import make_auto_mesh, set_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.models.gnn import SAGEConfig, init_params, random_graph
 from repro.models.gnn.graphsage import full_graph_forward
 
@@ -270,7 +270,7 @@ cfg1 = dataclasses.replace(cfg0, partitioned_edges=True)
 params = init_params(cfg0, jax.random.PRNGKey(0))
 dense = full_graph_forward(cfg0, params, {k: jnp.asarray(v) for k, v in g.items()})
 mesh = make_auto_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     out = jax.jit(lambda p, gr: full_graph_forward(cfg1, p, gr, mesh))(
         params, {k: jnp.asarray(v) for k, v in gp.items()})
 err = float(jnp.max(jnp.abs(out - dense)))

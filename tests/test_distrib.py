@@ -78,10 +78,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import make_auto_mesh, set_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.distrib import masked_psum_lookup
 from repro.distrib.compression import compressed_psum, CompressedAllReduce
-from repro.compat import shard_map
 
 mesh = make_auto_mesh((2, 4), ("data", "model"))
 
@@ -90,7 +89,7 @@ N, D, B, K = 64, 4, 8, 5
 rng = np.random.default_rng(0)
 table = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
 ids = jnp.asarray(rng.integers(0, N, size=(B, K)))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     lookup = masked_psum_lookup(mesh, batch_dims=2)
     got = jax.jit(lookup)(
         jax.device_put(table, NamedSharding(mesh, P("model", None))),
@@ -120,7 +119,7 @@ def body(g):
     out, _ = compressed_psum(g, "data", state)
     return out
 
-f = shard_map(body, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))
+f = jax.shard_map(body, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))
 out = jax.jit(f)(grads)
 # each data shard holds 4 rows; result = mean across the 2 data shards
 want = (np.asarray(grads[:4]) + np.asarray(grads[4:])) / 2

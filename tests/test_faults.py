@@ -494,6 +494,28 @@ def test_run_with_restarts_recovers_from_crash(tmp_path):
     assert any("relaunching" in m for m in logs)
 
 
+def test_restart_parent_initializes_no_backend(tmp_path):
+    """The --max-restarts parent must not hold the device its child needs:
+    no JAX backend may exist by the time the child is started."""
+    code = (
+        "import sys, repro.train\n"
+        "from jax._src import xla_bridge\n"
+        "def probe(argv, max_restarts):\n"
+        "    assert not xla_bridge.backends_are_initialized()\n"
+        "    return 0\n"
+        "repro.train.run_with_restarts = probe\n"
+        "from repro.launch import train\n"
+        f"sys.argv = ['train', '--sessions', '100', '--ckpt-dir', "
+        f"{str(tmp_path)!r}, '--max-restarts', '1']\n"
+        "train.main()\n")
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [src] + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 def test_run_with_restarts_budget_exhausted(tmp_path):
     script = tmp_path / "always_dies.py"
     script.write_text("import sys; sys.exit(3)\n")

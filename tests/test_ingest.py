@@ -92,6 +92,16 @@ def test_more_workers_than_shards(tmp_path):
     assert many[""].rows == 120 and many[""].n_shards == 2
 
 
+def test_ingest_workers_initialize_no_jax_backend(tmp_path, monkeypatch):
+    """A worker shares its host with the process that holds the chip: had
+    it initialized a JAX backend, it would claim the device. Spawned under
+    a platform no backend serves, any such initialization raises."""
+    monkeypatch.setenv("JAX_PLATFORMS", "no_such_platform")
+    store = ingest_synthetic(CFG, str(tmp_path / "s"), chunk_sessions=200,
+                             shard_rows=300, splits=SPLITS, workers=2)
+    assert sum(s.rows for s in store.values()) == CFG.n_sessions
+
+
 def _entry(i, rows=10):
     return {"name": f"shard_{i:05d}", "rows": rows}
 

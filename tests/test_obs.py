@@ -594,6 +594,22 @@ def test_profile_window_close_flushes_open_trace(monkeypatch):
     assert calls == ["start", "stop"]
 
 
+def test_profile_window_raises_when_trace_cannot_start(monkeypatch):
+    import jax.profiler
+
+    def refuse(_):
+        raise RuntimeError("no profiler")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    sink = MemorySink()
+    pw = ProfileWindow(0, 8, log_dir="prof", recorder=Recorder(sinks=[sink]))
+    with pytest.raises(RuntimeError, match="no profiler"):
+        pw.before_chunk(0)
+    assert not pw.active
+    pw.close(8)  # nothing open: no stop_trace call, no event
+    assert sink.by_kind("event") == []
+
+
 # -- streaming data plane -----------------------------------------------------
 @pytest.fixture()
 def store_dir(tmp_path, small_log):

@@ -529,3 +529,51 @@ def test_chaos_drill_counters_flow_to_recorder(registry, tmp_path):
     assert snap.get("serve.degraded", 0) > 0
     assert snap.get("serve.breaker_transitions", 0) > 0
     assert "serve.queue_depth:gauge" in snap
+
+
+# -- launcher exit status -----------------------------------------------------
+_LAUNCH_ARGS = ["--model", "pbm", "--pairs", "1000", "--requests", "8",
+                "--batch", "4", "--virtual-time"]
+
+
+@pytest.fixture()
+def serve_launcher(monkeypatch):
+    from repro.launch import serve as launcher
+
+    # Tests keep to the process's own compile cache settings.
+    monkeypatch.setattr(launcher, "enable_compile_cache", lambda: None)
+    return launcher
+
+
+def test_serve_launcher_returns_summary_when_all_primary(serve_launcher):
+    summary = serve_launcher.main(_LAUNCH_ARGS)
+    assert summary["answered"] == 8 and summary["degraded"] == 0
+
+
+def _fail_primary_traffic(monkeypatch):
+    from repro.serve.registry import ModelEntry
+
+    real_run = ModelEntry.run
+
+    def run(self, tier, batch):
+        # warmup calls run() directly; engine dispatches are counted first
+        if tier == "primary" and self.dispatches:
+            raise RuntimeError("device lost")
+        return real_run(self, tier, batch)
+
+    monkeypatch.setattr(ModelEntry, "run", run)
+
+
+def test_serve_launcher_fails_on_unasked_model_error(serve_launcher,
+                                                      monkeypatch):
+    _fail_primary_traffic(monkeypatch)
+    with pytest.raises(SystemExit, match="model errors"):
+        serve_launcher.main(_LAUNCH_ARGS)
+
+
+def test_serve_launcher_tolerates_degradation_a_drill_asked_for(
+        serve_launcher, monkeypatch):
+    _fail_primary_traffic(monkeypatch)
+    summary = serve_launcher.main(_LAUNCH_ARGS + ["--fault-poison-every",
+                                                  "5"])
+    assert summary["degraded"] > 0
