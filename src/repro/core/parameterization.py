@@ -179,6 +179,12 @@ class EmbeddingParameter(Module):
             "quotient-remainder compression has no single row-id stream")
 
     def __call__(self, params, batch):
+        # The scope names the hash, gather and relayout in the trace; autodiff
+        # names their backward (the gradient scatter) transpose(jvp(embed)).
+        with jax.named_scope("embed"):
+            return self._lookup(params, batch)
+
+    def _lookup(self, params, batch):
         c = self.config
         if c.compression in (Compression.NONE, Compression.HASH):
             logits = jnp.take(params["table"], self.row_ids(batch), axis=0)

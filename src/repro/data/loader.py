@@ -15,6 +15,8 @@ from typing import Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
+from repro.obs import get_recorder
+
 MODEL_KEYS = ("positions", "query_doc_ids", "clicks", "mask",
               "query_doc_features", "bias_features")
 
@@ -156,10 +158,16 @@ class DevicePrefetcher:
     then places each (stacked) batch directly into its sharded layout — or
     a callable ``batch -> device/sharding`` for per-batch placement (e.g.
     shard divisible batches, replicate the ``drop_last=False`` tail).
+
+    Spans (on ``recorder``, default the process-global one): ``stage``
+    around each item's stack and ``device_put`` (tag ``n``, the batches in
+    it), on whichever thread stages; ``batch_wait`` around each wait of the
+    consumer for the next item, the end-of-epoch marker included.
     """
 
     def __init__(self, loader, size: int = 2, device=None,
-                 chunk_batches: Optional[int] = None, overlap: bool = True):
+                 chunk_batches: Optional[int] = None, overlap: bool = True,
+                 recorder=None):
         if size < 1:
             raise ValueError(f"prefetch size must be >= 1, got {size}")
         if chunk_batches is not None and chunk_batches < 1:
@@ -177,6 +185,10 @@ class DevicePrefetcher:
         self.device = device
         self.chunk_batches = chunk_batches
         self.overlap = overlap
+        self.recorder = recorder
+
+    def _rec(self):
+        return self.recorder if self.recorder is not None else get_recorder()
 
     def _put(self, batch):
         import jax
@@ -194,9 +206,12 @@ class DevicePrefetcher:
         it (a generator must be closed from the thread executing it)."""
         it = iter(self.loader)
         get_state = getattr(self.loader, "state_dict", lambda: None)
+        rec = self._rec()
         if self.chunk_batches is None:
             for batch in it:
-                yield (self._put(batch), get_state())
+                with rec.span("stage", n=1):
+                    item = (self._put(batch), get_state())
+                yield item
             return
         pushback = []  # one-batch lookahead for the shape-change flush
         while True:
@@ -219,22 +234,26 @@ class DevicePrefetcher:
                 state = s
             if not batches:
                 return
-            chunk = {k: np.stack([b[k] for b in batches])
-                     for k in batches[0]}
-            yield (self._put(chunk), state, len(batches))
+            with rec.span("stage", n=len(batches)):
+                chunk = self._put({k: np.stack([b[k] for b in batches])
+                                   for k in batches[0]})
+            yield (chunk, state, len(batches))
 
     # -- execution modes -------------------------------------------------------
     def _pump(self, items):
         """Inline mode: prime ``size`` items, then refill one ahead of each
         yield, all on the consumer thread (``overlap=False``)."""
         queue = collections.deque()
+        rec = self._rec()
         try:
-            for item in items:
-                queue.append(item)
-                if len(queue) >= self.size:
-                    break
+            with rec.span("batch_wait"):
+                for item in items:
+                    queue.append(item)
+                    if len(queue) >= self.size:
+                        break
             while queue:
-                nxt = next(items, None)
+                with rec.span("batch_wait"):
+                    nxt = next(items, None)
                 if nxt is not None:  # refill before handing back to compute
                     queue.append(nxt)
                 yield queue.popleft()
@@ -274,17 +293,22 @@ class DevicePrefetcher:
                 # unwinds its epoch generator's finally (read-ahead shutdown)
                 items.close()
 
+        def take():
+            while True:
+                try:
+                    return q.get(timeout=0.2)
+                except queue_mod.Empty:
+                    if not thread.is_alive() and q.empty() and not fail:
+                        return done  # crashed harder than except: nothing to raise
+
         thread = threading.Thread(target=run, daemon=True,
                                   name="device-prefetch")
         thread.start()
+        rec = self._rec()
         try:
             while True:
-                try:
-                    item = q.get(timeout=0.2)
-                except queue_mod.Empty:
-                    if not thread.is_alive() and q.empty() and not fail:
-                        return  # crashed harder than except: nothing to raise
-                    continue
+                with rec.span("batch_wait"):
+                    item = take()
                 if item is done:
                     if fail:
                         raise fail[0]  # original traceback intact

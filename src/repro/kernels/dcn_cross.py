@@ -48,5 +48,6 @@ def dcn_cross_pallas(x0: jax.Array, x: jax.Array, w: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((block_b, Dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, Dp), jnp.float32),
         interpret=interpret,
+        name="dcn_cross",
     )(x0, x, w, b.reshape(1, -1))
     return out[:B, :D]

@@ -103,5 +103,6 @@ def embedding_bag_pallas(table: jax.Array, ids: jax.Array, weights: jax.Array,
         scratch_shapes=[pltpu.VMEM((L, bb, Dp), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="embedding_bag",
     )(safe_ids, weights, table)
     return out[:B, :D]

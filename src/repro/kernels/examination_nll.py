@@ -141,6 +141,7 @@ def examination_nll_partials(attr_logits: jax.Array, clicks: jax.Array,
         out_specs=[pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0))] * 2,
         out_shape=[jax.ShapeDtypeStruct((grid[0], 1, 1), jnp.float32)] * 2,
         interpret=interpret,
+        name="examination_nll",
     )(*inputs)
 
 

@@ -113,5 +113,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, Dh), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out[:, :, :Sq, :]

@@ -42,5 +42,6 @@ def fm_interaction_pallas(v: jax.Array, *, block_b: int = 128,
         out_specs=pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         interpret=interpret,
+        name="fm_interaction",
     )(v)
     return out[:B, 0]

@@ -39,6 +39,10 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fm_interaction import fm_interaction_pallas
 from repro.kernels.session_nll import session_nll_partials
 
+#: ``jax.named_scope`` of the fused losses, forward and backward: the trace
+#: attributes their device time by it (``op_name`` metadata).
+LOSS_SCOPE = "loss"
+
 # Re-exported so callers can flip impls without importing the registry module.
 override_impl = _dispatch.override_impl
 set_impl_override = _dispatch.set_impl_override
@@ -230,13 +234,16 @@ def _nll_fwd(logits, clicks, mask, impl):
 
 def _nll_bwd(impl, res, g):
     logits, clicks, mask = res
-    x = logits.astype(jnp.float32)
-    c = clicks.astype(jnp.float32)
-    m = mask.astype(jnp.float32)
-    inv_count = 1.0 / jnp.maximum(jnp.sum(m), 1.0)
-    # d nll/dx = sigmoid(x) - c; d nll/dc = -x; both masked-mean weighted.
-    d_logits = (g * (jax.nn.sigmoid(x) - c) * m * inv_count).astype(logits.dtype)
-    d_clicks = (g * (-x) * m * inv_count).astype(clicks.dtype)
+    # A custom_vjp backward is traced outside the forward's scope.
+    with jax.named_scope(LOSS_SCOPE):
+        x = logits.astype(jnp.float32)
+        c = clicks.astype(jnp.float32)
+        m = mask.astype(jnp.float32)
+        inv_count = 1.0 / jnp.maximum(jnp.sum(m), 1.0)
+        # d nll/dx = sigmoid(x) - c; d nll/dc = -x; both masked-mean weighted.
+        d_logits = (g * (jax.nn.sigmoid(x) - c) * m
+                    * inv_count).astype(logits.dtype)
+        d_clicks = (g * (-x) * m * inv_count).astype(clicks.dtype)
     return d_logits, d_clicks, None
 
 
@@ -252,7 +259,8 @@ def session_nll(logits: jax.Array, clicks: jax.Array, mask: jax.Array,
     the per-element log-probability intermediates.
     """
     impl = _dispatch.resolve_impl("session_nll", impl)
-    return _session_nll(logits, clicks, mask, impl)
+    with jax.named_scope(LOSS_SCOPE):
+        return _session_nll(logits, clicks, mask, impl)
 
 
 # ---------------------------------------------------------------------------
@@ -279,8 +287,9 @@ def _exam_bwd(impl, res, g):
     def composed(x_, c_, ss_, d_, r_, rn_):
         return _ref.examination_nll_ref(x_, c_, mask, ss_, d_, r_, rn_)
 
-    _, vjp = jax.vjp(composed, x, clicks, pss, pd, pr, prn)
-    dx, dc, dss, dd, dr, drn = vjp(g)
+    with jax.named_scope(LOSS_SCOPE):
+        _, vjp = jax.vjp(composed, x, clicks, pss, pd, pr, prn)
+        dx, dc, dss, dd, dr, drn = vjp(g)
     return dx, dc, None, dss, dd, dr, drn
 
 
@@ -302,8 +311,9 @@ def examination_nll(attr_logits: jax.Array, clicks: jax.Array,
     kernels/examination_nll.py for the lowering and the numerics contract.
     """
     impl = _dispatch.resolve_impl("examination_nll", impl)
-    return _examination_nll(attr_logits, clicks, mask, p_skip_survive,
-                            p_death, p_reset, p_reset_not, impl)
+    with jax.named_scope(LOSS_SCOPE):
+        return _examination_nll(attr_logits, clicks, mask, p_skip_survive,
+                                p_death, p_reset, p_reset_not, impl)
 
 
 # ---------------------------------------------------------------------------

@@ -54,4 +54,5 @@ def session_nll_partials(logits: jax.Array, clicks: jax.Array,
         out_specs=[pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0))] * 2,
         out_shape=[jax.ShapeDtypeStruct((grid[0], 1, 1), jnp.float32)] * 2,
         interpret=interpret,
+        name="session_nll",
     )(logits, clicks.astype(logits.dtype), m.astype(logits.dtype))

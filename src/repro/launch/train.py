@@ -227,10 +227,6 @@ def main():
                          "dispatches covering global steps A..B")
     ap.add_argument("--profile-dir", default="profile",
                     help="directory the --profile-steps trace is written to")
-    ap.add_argument("--emit-roofline", action="store_true",
-                    help="emit the compiled chunk step's static HLO cost "
-                         "(flops/bytes, while-loops scaled by trip count) as "
-                         "a roofline telemetry event (one extra AOT compile)")
     args = ap.parse_args()
     if args.ingest_workers < 1:
         ap.error(f"--ingest-workers must be >= 1, got {args.ingest_workers}")
@@ -351,8 +347,7 @@ def main():
                       telemetry=bool(args.metrics_out),
                       obs_every=args.obs_every,
                       profile_steps=args.profile_steps,
-                      profile_dir=args.profile_dir,
-                      emit_roofline=args.emit_roofline)
+                      profile_dir=args.profile_dir)
     try:
         trainer.train(model, train_loader, val_loader,
                       resume=bool(args.ckpt_dir))

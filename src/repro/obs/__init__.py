@@ -1,7 +1,9 @@
 """``repro.obs`` — zero-sync observability: on-device metrics drained with
-the loss stream, host wall-time spans with Chrome-trace export, pluggable
-event sinks, counters/gauges, process stats, and programmatic profiler
-windows. See README "Observability"."""
+the loss stream, host wall-time spans with Chrome-trace export (annotated
+into profiler traces too), compile spans, pluggable event sinks,
+counters/gauges, process stats, and programmatic profiler windows. See
+README "Observability"."""
+from repro.obs.compiles import watch_compiles
 from repro.obs.events import EVENT_KINDS, make_event, validate_event
 from repro.obs.profiler import ProfileWindow, parse_profile_steps
 from repro.obs.recorder import (Recorder, configure, get_recorder,
@@ -30,4 +32,5 @@ __all__ = [
     "TelemetryDrain",
     "ProfileWindow",
     "parse_profile_steps",
+    "watch_compiles",
 ]

@@ -11,7 +11,6 @@ An event is a flat JSON-able dict. Required fields:
     - ``"counters"`` — a snapshot of monotonically accumulated counters and
       last-value gauges (``data``);
     - ``"process"``  — host/device process stats (RSS, device memory);
-    - ``"roofline"`` — static HLO cost of a compiled program (``data``);
     - ``"epoch"``    — one trainer epoch record (``data`` mirrors history).
 * ``name`` — the series/span/occurrence name (``"train_step"``,
   ``"shard_read"``, ...).
@@ -31,8 +30,7 @@ import numbers
 import time
 from typing import Any, Dict, Optional
 
-EVENT_KINDS = ("metric", "span", "event", "counters", "process", "roofline",
-               "epoch")
+EVENT_KINDS = ("metric", "span", "event", "counters", "process", "epoch")
 
 _INT_FIELDS = ("step", "epoch", "replica")
 

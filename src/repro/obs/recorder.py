@@ -62,6 +62,14 @@ class Recorder:
         on_close = self._span_to_sinks if self.sinks else None
         return self.tracer.span(name, on_close=on_close, **tags)
 
+    def record_span(self, name: str, start: float, duration: float,
+                    **tags) -> None:
+        """A span measured after the fact (``start`` on the wall clock, as
+        ``time.time()``; ``duration`` in seconds): kept and forwarded like
+        one from :meth:`span`, but with no profiler annotation."""
+        on_close = self._span_to_sinks if self.sinks else None
+        self.tracer.record(name, start, duration, on_close=on_close, **tags)
+
     def _span_to_sinks(self, s):
         self.emit(make_event("span", s.name, s.duration, t=s.t_start,
                              **s.tags))

@@ -7,6 +7,14 @@ span *sees* is therefore host-side time: an epoch span covers dispatch +
 drain, not device busy time (use the ``jax.profiler`` hooks in
 :mod:`repro.obs.profiler` for device timelines).
 
+Each span also opens a ``jax.profiler.TraceAnnotation`` with its name and
+tags, so that while a profiler trace is open it lands on the host plane of
+the ``.xplane.pb``, on the same clock as the device's operations. A span
+tagged ``step_num`` is a ``StepTraceAnnotation``, which xprof's step view
+reads. The annotation is opened only where ``jax`` is already imported:
+importing :mod:`repro.obs` stays jax-free (the parallel-ingest worker
+processes rely on that).
+
 Completed spans land in a ``deque(maxlen=capacity)`` ring buffer (old spans
 fall off; a week-long run cannot OOM on its own telemetry) and are
 exportable as Chrome-trace JSON (``chrome://tracing`` / Perfetto's
@@ -14,9 +22,11 @@ exportable as Chrome-trace JSON (``chrome://tracing`` / Perfetto's
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -31,6 +41,18 @@ class Span:
     duration: float       # seconds, from perf_counter
     thread_id: int
     tags: Dict[str, Any]
+
+
+def _annotation(name: str, tags: Dict[str, Any]):
+    """The profiler's annotation of a span, or nothing where jax has not
+    been imported (a profiler trace then cannot be open either)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    if "step_num" in tags:
+        return profiler.StepTraceAnnotation(name, **tags)
+    return profiler.TraceAnnotation(name, **tags)
 
 
 class SpanTracer:
@@ -48,18 +70,26 @@ class SpanTracer:
         """
         depth = getattr(self._depth, "d", 0)
         self._depth.d = depth + 1
-        t_wall = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            self._depth.d = depth
-            s = Span(name=name, t_start=t_wall, duration=dur,
-                     thread_id=threading.get_ident(), tags=dict(tags))
-            self.spans.append(s)
-            if on_close is not None:
-                on_close(s)
+        with _annotation(name, tags):
+            t_wall = time.time()
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dur = time.perf_counter() - t0
+                self._depth.d = depth
+                self.record(name, t_wall, dur, on_close=on_close, **tags)
+
+    def record(self, name: str, t_start: float, duration: float,
+               on_close=None, **tags) -> Span:
+        """Record a span measured after the fact (``t_start`` on the wall
+        clock, ``duration`` in seconds), such as a compile."""
+        s = Span(name=name, t_start=t_start, duration=duration,
+                 thread_id=threading.get_ident(), tags=dict(tags))
+        self.spans.append(s)
+        if on_close is not None:
+            on_close(s)
+        return s
 
     def clear(self):
         self.spans.clear()

@@ -92,7 +92,11 @@ class TelemetryDrain:
         # parallel-ingest worker processes) stays jax-free; only the one
         # method that touches device memory pays the jax import.
         import jax
-        data = jax.device_get(payload)
+        rec = self._rec()
+        # "drain": the host blocked on the chunk's losses (its headroom
+        # over the device).
+        with rec.span("drain", step=first_step):
+            data = jax.device_get(payload)
         if isinstance(data, dict):
             losses = np.asarray(data["loss"])
             skipped = (np.asarray(data["skipped"])
@@ -123,7 +127,6 @@ class TelemetryDrain:
                 self.skipped_steps += skipped.sum(axis=0)
         start = self.n_batches if first_step is None else first_step
         self.n_batches += n
-        rec = self._rec()
         if rec.enabled:
             self._emit(rec, losses, skipped, extras, start)
 

@@ -293,6 +293,11 @@ class TrainEngine:
 
     # -- the scanned step ------------------------------------------------------
     def _apply_update(self, params, opt_state, grads, batch):
+        # The trace attributes the optimizer's device time by this scope.
+        with jax.named_scope("update"):
+            return self._update(params, opt_state, grads, batch)
+
+    def _update(self, params, opt_state, grads, batch):
         if not self.sparse_parts:
             updates, opt_state = self.optimizer.update(grads, opt_state, params)
             return optim_lib.apply_updates(params, updates), opt_state
@@ -493,19 +498,3 @@ class TrainEngine:
         :meth:`step` runs; a cache hit once ``step`` has run them)."""
         with self._mesh_context():
             return self._step.lower(params, opt_state, chunk).compile()
-
-    def roofline(self, params, opt_state, chunk) -> Dict[str, Any]:
-        """Static per-dispatch cost of the compiled chunk step: lower +
-        compile for these arg shapes and run the while-aware HLO cost model
-        (:func:`repro.launch.hlo_cost.analyze_hlo`), so the scan body is
-        scaled by its trip count. This is an extra AOT compile of the same
-        program — gate it behind a flag (``Trainer(emit_roofline=True)``
-        emits it once, as a ``roofline`` telemetry event)."""
-        from repro.launch.hlo_cost import analyze_hlo
-
-        hlo = self.compiled(params, opt_state, chunk).as_text()
-        cost = analyze_hlo(hlo)
-        n = jax.tree_util.tree_leaves(chunk)[0].shape[0]
-        cost["chunk_batches"] = int(n)
-        cost["flops_per_step"] = cost["flops"] / max(n, 1)
-        return cost

@@ -39,7 +39,7 @@ from repro.core.metrics import (ConditionalPerplexity, LogLikelihood, MultiMetri
                                 Perplexity)
 from repro.data.loader import DevicePrefetcher
 from repro.obs import (ProfileWindow, TelemetryDrain, get_recorder, make_event,
-                       parse_profile_steps)
+                       parse_profile_steps, watch_compiles)
 from repro.train.checkpoints import CheckpointManager
 from repro.train.engine import TrainEngine
 from repro.train.fault_tolerance import PreemptionHandler, StepWatchdog
@@ -82,8 +82,7 @@ class Trainer:
                  recorder=None,
                  obs_every: int = 1,
                  profile_steps: Optional[str] = None,
-                 profile_dir: Optional[str] = None,
-                 emit_roofline: bool = False):
+                 profile_dir: Optional[str] = None):
         self.optimizer = optimizer
         self.epochs = epochs
         self.patience = patience
@@ -103,8 +102,7 @@ class Trainer:
         # obs.configure() still takes effect); `obs_every` rate-limits
         # per-step metric events; `profile_steps` ("A:B") opens a
         # jax.profiler window into `profile_dir` around those global
-        # steps; `emit_roofline` emits the chunk step's static HLO cost
-        # once per train() (one extra AOT compile).
+        # steps.
         self.telemetry = bool(telemetry)
         self.recorder = recorder
         self.obs_every = int(obs_every)
@@ -112,7 +110,6 @@ class Trainer:
                               if isinstance(profile_steps, str)
                               else profile_steps)
         self.profile_dir = profile_dir
-        self.emit_roofline = bool(emit_roofline)
         self.chunk_batches = chunk_batches
         self.mesh = mesh
         self.sparse_tables = sparse_tables
@@ -208,6 +205,7 @@ class Trainer:
     def train(self, model, train_loader, val_loader=None,
               state: Optional[TrainState] = None,
               resume: bool = False) -> List[Dict[str, float]]:
+        watch_compiles()
         engine = self.engine = self._make_engine(model)
         R = self.replicas
         if state is None:
@@ -271,7 +269,6 @@ class Trainer:
                                  log_dir=self.profile_dir or "profile",
                                  recorder=self.recorder)
                    if self.profile_steps else None)
-        roofline_pending = self.emit_roofline
         if R is None:
             best_val = float("inf")
             bad_epochs = 0
@@ -345,24 +342,13 @@ class Trainer:
                 chunk_t0 = time.time()
                 for chunk, loader_state, n in DevicePrefetcher(
                         train_loader, chunk_batches=engine.chunk_batches,
-                        device=engine.batch_sharding()):
-                    if roofline_pending:
-                        # One extra AOT compile of the already-traced program;
-                        # emitted once, before the first dispatch donates the
-                        # argument buffers.
-                        roofline_pending = False
-                        with rec.span("roofline"):
-                            cost = engine.roofline(state.params,
-                                                   state.opt_state, chunk)
-                        rec.emit(make_event("roofline", "chunk_step",
-                                            data=cost,
-                                            step=state.global_step))
+                        device=engine.batch_sharding(),
+                        recorder=self.recorder):
                     if profile is not None:
                         profile.before_chunk(state.global_step)
-                    if R is None:
-                        state.params, state.opt_state, losses = engine.step(
-                            state.params, state.opt_state, chunk)
-                    else:
+                    # A step annotation for xprof: its step_num is the
+                    # chunk's first global step.
+                    with rec.span("dispatch", step_num=state.global_step):
                         state.params, state.opt_state, losses = engine.step(
                             state.params, state.opt_state, chunk,
                             active=epoch_active)
@@ -519,7 +505,8 @@ class Trainer:
         m_state = None
         if self.mesh is None and self.chunk_batches > 1:
             for chunk, _, _ in DevicePrefetcher(
-                    loader, chunk_batches=self.chunk_batches):
+                    loader, chunk_batches=self.chunk_batches,
+                    recorder=self.recorder):
                 if m_state is None:
                     m_state = metrics.init_state(chunk["positions"].shape[2],
                                                  replicas=replicas)
@@ -546,7 +533,8 @@ class Trainer:
                 def device(batch):
                     rows = next(iter(batch.values())).shape[0]
                     return split if rows % dp == 0 else replicated
-            for batch, _ in DevicePrefetcher(loader, device=device):
+            for batch, _ in DevicePrefetcher(loader, device=device,
+                                             recorder=self.recorder):
                 if m_state is None:
                     m_state = metrics.init_state(batch["positions"].shape[1],
                                                  replicas=replicas)

@@ -499,37 +499,154 @@ def test_trainer_scalar_skip_events_at_poisoned_steps(small_log):
 
 
 def test_trainer_emits_spans_and_roofline(small_log):
+    # The roofline event (a second compile for an HLO cost estimate) is
+    # gone; the trainer's spans remain.
     cfg, data = small_log
     model = _model(cfg)
     sink = MemorySink()
     rec = Recorder(sinks=[sink])
     trainer = Trainer(optim.adamw(0.05), epochs=1, patience=100,
-                      chunk_batches=4, recorder=rec, emit_roofline=True,
+                      chunk_batches=4, recorder=rec,
                       log_fn=lambda *_: None)
     trainer.train(model, ClickLogLoader(data, batch_size=64, seed=5),
                   ClickLogLoader(data, batch_size=256, shuffle=False,
                                  drop_last=False))
     span_names = {e["name"] for e in sink.by_kind("span")}
-    assert {"epoch", "eval", "roofline"} <= span_names
-    (rf,) = sink.by_kind("roofline")
-    assert rf["data"]["bytes"] > 0 and rf["data"]["chunk_batches"] == 4
-    assert rf["data"]["unknown_trip_loops"] == 0  # scan trip count resolved
+    assert {"epoch", "eval", "dispatch", "drain", "batch_wait",
+            "stage"} <= span_names
+    assert "roofline" not in EVENT_KINDS
+    assert not any(e["kind"] == "roofline" for e in sink.events)
 
 
-def test_engine_roofline_scales_with_chunk(small_log):
+def test_trainer_spans_once_per_chunk(small_log):
     cfg, data = small_log
-    model = _model(cfg)
+    sink = MemorySink()
+    trainer = Trainer(optim.adamw(0.05), epochs=1, patience=100,
+                      chunk_batches=3, recorder=Recorder(sinks=[sink]),
+                      log_fn=lambda *_: None)
+    trainer.train(_model(cfg), ClickLogLoader(data, batch_size=64, seed=5))
+    chunks = 3   # 600 sessions: 9 batches of 64
 
-    def cost(n):
-        eng = TrainEngine(model, optim.adamw(0.05), chunk_batches=n)
-        params = model.init(jax.random.PRNGKey(0))
-        return eng.roofline(params, eng.init_opt_state(params),
-                            _chunk(data, n=n))
+    def spans(name):
+        return [e for e in sink.by_kind("span") if e["name"] == name]
 
-    c2, c4 = cost(2), cost(4)
-    assert c4["chunk_batches"] == 4 and c2["chunk_batches"] == 2
-    # while-aware: doubling the scan trip count ~doubles traffic
-    assert c4["bytes"] > 1.5 * c2["bytes"]
+    dispatch = spans("dispatch")
+    assert len(dispatch) == chunks
+    # a step annotation: tagged with each chunk's first global step
+    assert [e["tags"]["step_num"] for e in dispatch] == [0, 3, 6]
+    assert [e["step"] for e in spans("drain")] == [0, 3, 6]
+    assert [e["tags"]["n"] for e in spans("stage")] == [3] * chunks
+    # one wait per chunk, and the last for the end of the epoch
+    assert len(spans("batch_wait")) == chunks + 1
+
+
+@pytest.mark.parametrize("model_name", ["dbn", "dctr"])
+def test_chunk_step_ops_carry_their_scopes(model_name):
+    import re
+
+    from repro.core import (MODEL_REGISTRY, Compression,
+                            EmbeddingParameterConfig)
+
+    table = EmbeddingParameterConfig(
+        parameters=1 << 16, compression=Compression.HASH,
+        compression_ratio=10.0, baseline_correction=True, init_logit=-2.0)
+    tables = ({"attraction": table, "satisfaction": table}
+              if model_name == "dbn" else {"attraction": table})
+    model = MODEL_REGISTRY[model_name](positions=10, **tables)
+    engine = TrainEngine(model, optim.adamw(0.003), chunk_batches=2)
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    shape = (2, 64, 10)
+    chunk = {"positions": np.broadcast_to(np.arange(1, 11, dtype=np.int32),
+                                          shape).copy(),
+             "query_doc_ids": rng.integers(0, 1 << 20, shape,
+                                           dtype=np.int32),
+             "clicks": rng.integers(0, 2, shape).astype(np.int32),
+             "mask": np.ones(shape, bool)}
+    text = engine.compiled(params, engine.init_opt_state(params),
+                           chunk).as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+
+    def has(pattern):
+        return any(re.search(pattern, n) for n in names)
+
+    assert has(r"/jvp\(embed\)/")                  # hash, gather
+    assert has(r"/transpose\(jvp\(embed\)\)/")     # gradient scatter
+    assert has(r"/jvp\(loss\)/")                   # fused loss forward
+    assert has(r"/transpose\(jvp\(loss\)\)/loss/")  # its custom backward
+    assert has(r"/update/")                         # AdamW
+
+
+def test_recorder_span_lands_on_the_profiler_host_plane(tmp_path):
+    rec = Recorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("shard_read", shard=7):
+            jnp.ones(4).block_until_ready()
+        with rec.span("dispatch", step_num=12):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    found = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("shard_read", "dispatch"):
+                        found[e.name] = (e.duration_ns, dict(e.stats))
+    assert set(found) == {"shard_read", "dispatch"}
+    assert str(found["shard_read"][1]["shard"]) == "7"
+    assert str(found["dispatch"][1]["step_num"]) == "12"
+    (span,) = [s for s in rec.tracer.spans if s.name == "shard_read"]
+    # the annotation encloses the span's own timing
+    assert found["shard_read"][0] >= span.duration * 1e9
+
+
+def test_importing_obs_leaves_jax_unimported():
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys, repro.obs, repro.data\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n"
+            "with repro.obs.span('shard_read', shard=1):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'span imported jax'\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+def test_fresh_jit_emits_compile_span_and_counters():
+    from repro.obs import get_recorder, set_recorder, watch_compiles
+
+    def triple_plus_one(x):
+        return x * 3.0 + 1.0
+
+    x = jnp.arange(7.0).block_until_ready()
+    sink = MemorySink()
+    rec = Recorder(sinks=[sink])
+    previous = get_recorder()
+    set_recorder(rec)
+    try:
+        watch_compiles()
+        watch_compiles()   # idempotent: one listener per process
+        t0 = time.time()
+        jax.jit(triple_plus_one)(x).block_until_ready()
+    finally:
+        set_recorder(previous)
+    compiles = sink.by_name("compile", kind="span")
+    ours = [e["tags"]["stage"] for e in compiles
+            if "triple_plus_one" in e["tags"]["fun"]]
+    assert sorted(ours) == ["backend", "lower", "trace"]
+    backends = [e for e in compiles if e["tags"]["stage"] == "backend"]
+    assert rec.counters["compile.count"] == len(backends) >= 1
+    assert rec.counters["compile.seconds"] == pytest.approx(
+        sum(e["value"] for e in compiles))
+    assert all(e["t"] >= t0 - 1e-3 for e in compiles)
 
 
 # -- watchdog + profiler hooks ------------------------------------------------
