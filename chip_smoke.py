@@ -262,15 +262,21 @@ def train(kind: str, store: str, batch: int, epochs: int = 1, mesh=None,
 def check_kernel_ran(kind: str, trainer, store: str, batch: int,
                      kernel: str) -> None:
     """The chunk program the trainer dispatched holds a Mosaic kernel."""
+    import jax
+
+    from repro.core.parameterization import relayout_scalar_tables
     from repro.data import DevicePrefetcher, StreamingClickLogLoader
 
     loader = StreamingClickLogLoader(store, batch_size=batch, seed=SEED)
     for chunk, _, _ in DevicePrefetcher(loader, chunk_batches=CHUNK):
         break
     loader.close()
-    state = trainer.final_state
-    text = trainer.engine.compiled(state.params, state.opt_state,
-                                   chunk).as_text()
+    engine, state = trainer.engine, trainer.final_state
+    # The shapes the chunk ran on: the final state is in the model's own.
+    params, opt_state = jax.eval_shape(
+        lambda t: relayout_scalar_tables(engine.model, t, flat=True),
+        (state.params, state.opt_state))
+    text = engine.compiled(params, opt_state, chunk).as_text()
     check(KERNEL_MARK in text,
           f"{kind}: no {KERNEL_MARK} in the compiled chunk: {kernel} did "
           "not run as a kernel")

@@ -18,10 +18,11 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-from typing import Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.nn import Dense, DeepCrossV2, MLP, init as initializers
 from repro.nn.module import Module, split_rngs
@@ -179,25 +180,95 @@ class EmbeddingParameter(Module):
             "quotient-remainder compression has no single row-id stream")
 
     def __call__(self, params, batch):
-        # The scope names the hash, gather and relayout in the trace; autodiff
-        # names their backward (the gradient scatter) transpose(jvp(embed)).
+        # The scope names the hash and gather in the trace; autodiff names
+        # their backward (the gradient scatter) transpose(jvp(embed)).
         with jax.named_scope("embed"):
             return self._lookup(params, batch)
 
     def _lookup(self, params, batch):
         c = self.config
+        ids = batch[c.use_feature]
         if c.compression in (Compression.NONE, Compression.HASH):
             logits = jnp.take(params["table"], self.row_ids(batch), axis=0)
         else:  # QR: element-wise product of quotient and remainder rows
-            ids = batch[c.use_feature]
             q = jnp.take(params["quotient"], (ids // self.rem_rows) % self.quot_rows, axis=0)
             r = jnp.take(params["remainder"], ids % self.rem_rows, axis=0)
             logits = q * r
         if c.baseline_correction:
             logits = logits + params["baseline"]
-        if c.features == 1:
+        if c.features == 1 and logits.ndim > ids.ndim:  # a (rows, 1) table
             logits = jnp.squeeze(logits, axis=-1)
         return logits
+
+    def scalar_tables(self) -> Dict[str, int]:
+        """``{leaf: rows}`` of the tables this part gathers single logits
+        from (none when ``features > 1``)."""
+        if self.features != 1:
+            return {}
+        if self.config.compression == Compression.QR:
+            return {"quotient": self.quot_rows, "remainder": self.rem_rows}
+        return {"table": self.table_rows}
+
+
+def _path_names(path) -> List[str]:
+    """The names along a pytree path; a key that joins names with ``/``
+    (the sparse optimizer's ``"attraction/table"``) counts as each name."""
+    out: List[str] = []
+    for k in path:
+        for attr in ("key", "name", "idx"):
+            if hasattr(k, attr):
+                out.extend(str(getattr(k, attr)).split("/"))
+                break
+    return out
+
+
+def relayout_scalar_tables(model, tree, *, flat: bool, consume: bool = False):
+    """``tree`` with each scalar table of ``model``'s embedding parts, and
+    every leaf made from one under the same names (its gradient, optimizer
+    moments, replica stacks), as ``(..., rows)`` when ``flat`` and as the
+    ``(..., rows, 1)`` that :meth:`EmbeddingParameter.init` gives otherwise.
+
+    The lookup reads either. Training keeps them flat: the TPU lays out a
+    ``(rows, 1)`` array apart from the ``(rows,)`` that its gather and
+    scatter-add work in, so a step on ``(rows, 1)`` tables copies each table
+    into the gather's layout and its gradient out of the scatter's, in full.
+
+    With ``consume``, each leaf that moves is made and waited for before its
+    old buffer is deleted, one at a time, so that a state of table-sized
+    leaves is never held twice; ``tree``'s arrays are then spent, as a
+    donated argument's are.
+    """
+    tables = {(name, leaf): rows
+              for name, part in (getattr(model, "parts", None) or {}).items()
+              if isinstance(part, EmbeddingParameter)
+              for leaf, rows in part.scalar_tables().items()}
+    if not tables:
+        return tree
+    moved: Dict[int, object] = {}
+
+    def move(path, x):
+        if id(x) in moved:  # one array under two names
+            return moved[id(x)]
+        names = _path_names(path)
+        rows = next((r for (part, leaf), r in tables.items()
+                     if any(names[i:i + 2] == [part, leaf]
+                            for i in range(len(names) - 1))), None)
+        if rows is None:
+            return x
+        shape = tuple(np.shape(x))
+        if flat and shape[-2:] == (rows, 1):
+            new = x.reshape(shape[:-1])
+        elif not flat and shape[-1:] == (rows,) and shape[-2:] != (rows, 1):
+            new = x.reshape(shape + (1,))
+        else:
+            return x
+        if consume and isinstance(x, jax.Array):
+            new.block_until_ready()
+            x.delete()
+        moved[id(x)] = new
+        return new
+
+    return jax.tree_util.tree_map_with_path(move, tree)
 
 
 class PositionParameter(Module):

@@ -54,8 +54,8 @@ import jax.numpy as jnp
 
 class SparseTableState(NamedTuple):
     count: jax.Array  # global step (for bias correction)
-    mu: jax.Array     # (R, d) first moment
-    nu: jax.Array     # (R, d) second moment
+    mu: jax.Array     # first moment, shaped like the table
+    nu: jax.Array     # second moment, shaped like the table
 
 
 def init_sparse_table_state(table: jax.Array,
@@ -86,15 +86,16 @@ def unique_rows_with_sentinel(ids: jax.Array, n_rows: int, *,
 def sparse_row_grads(row_grads: jax.Array, ids: jax.Array, n_rows: int,
                      max_unique: int | None = None
                      ) -> Tuple[jax.Array, jax.Array]:
-    """Dedupe (N, d) per-lookup grads into (U, d) per-unique-row grads.
+    """Dedupe per-lookup grads (ids' shape plus the table's row shape: none
+    for a 1-D scalar table, (d,) for a wide one) into per-unique-row grads.
 
-    Returns (unique_ids (U,), grads (U, d)) with U = min(N, max_unique or N);
+    Returns (unique_ids (U,), grads (U, *row)) with U = min(N, max_unique or N);
     surplus slots hold the out-of-range sentinel ``n_rows`` (zero gradient),
     which :func:`sparse_adamw_update` scatters with ``mode="drop"`` — a true
     no-op that touches no real row.
     """
     flat_ids = ids.reshape(-1)
-    g = row_grads.reshape(flat_ids.shape[0], -1)
+    g = row_grads.reshape(flat_ids.shape[0], *row_grads.shape[ids.ndim:])
     unique_ids, inv = unique_rows_with_sentinel(
         flat_ids, n_rows, return_inverse=True, max_unique=max_unique)
     grads = jax.ops.segment_sum(g, inv.reshape(-1),

@@ -28,6 +28,7 @@ from typing import Dict, Iterable, Optional
 import jax
 import numpy as np
 
+from repro.core.parameterization import relayout_scalar_tables
 from repro.distrib.compression import quantize_tree, tree_nbytes
 from repro.serve.request import TIERS, make_request
 
@@ -43,6 +44,9 @@ class ModelEntry:
                  quantize_min_size: int = 512, service_model=None):
         self.name = name
         self.model = model
+        # Served from flat scalar tables, the layout the gather reads: a
+        # (rows, 1) table would be copied into it in full every batch.
+        params = relayout_scalar_tables(model, params, flat=True)
         self.params = params
         self.n_pairs = int(n_pairs)
         self.positions = int(model.positions)

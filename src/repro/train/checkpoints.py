@@ -83,6 +83,20 @@ def _leaf_crc32(arr: np.ndarray) -> str:
     return f"{zlib.crc32(np.ascontiguousarray(arr).tobytes()):08x}"
 
 
+def _conform(key: str, arr: np.ndarray, like) -> np.ndarray:
+    """``arr`` in the shape of the template leaf ``like``. Shapes that differ
+    only by axes of length 1 hold the same values in the same order: a
+    scalar table, which training holds as ``(rows,)``, restores into the
+    model's ``(rows, 1)`` and back. Any other difference is an error."""
+    shape = tuple(np.shape(like))
+    if arr.shape == shape:
+        return arr
+    if [d for d in arr.shape if d != 1] == [d for d in shape if d != 1]:
+        return arr.reshape(shape)
+    raise ValueError(f"checkpoint leaf {key!r} has shape {arr.shape}, "
+                     f"the template {shape}")
+
+
 def _fsync_file(path: str) -> None:
     fd = os.open(path, os.O_RDONLY)
     try:
@@ -179,10 +193,10 @@ class CheckpointManager:
         else:
             flat, treedef = _flatten_with_paths(like)
             leaves = []
-            for key in flat:
+            for key, want in flat.items():
                 if key not in arrays:
                     raise KeyError(f"checkpoint missing leaf {key!r}")
-                leaves.append(arrays[key])
+                leaves.append(_conform(key, arrays[key], want))
             # order must match tree_flatten order of `like`
             paths_in_order = list(flat.keys())
             restored = dict(zip(paths_in_order, leaves))

@@ -78,7 +78,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim as optim_lib
-from repro.core.parameterization import Compression, EmbeddingParameter
+from repro.core.parameterization import (Compression, EmbeddingParameter,
+                                        relayout_scalar_tables)
 from repro.optim.sparse import (init_sparse_table_state, sparse_adamw_update,
                                 unique_rows_with_sentinel)
 
@@ -275,9 +276,14 @@ class TrainEngine:
         return data_parallel_size(self.mesh)
 
     def place(self, params, opt_state):
-        """device_put params/opt-state with ``clax_param_rule`` shardings
-        (big tables row-sharded over 'model', everything else replicated).
-        No-op without a mesh."""
+        """The state in the form the step runs on: scalar embedding tables
+        and their moments flat (see
+        :func:`~repro.core.parameterization.relayout_scalar_tables`; the
+        arguments' arrays are spent), then, given a mesh, device_put with
+        ``clax_param_rule`` shardings (big tables row-sharded over 'model',
+        everything else replicated). :meth:`unplace` undoes the relayout."""
+        params, opt_state = relayout_scalar_tables(
+            self.model, (params, opt_state), flat=True, consume=True)
         if self.mesh is None:
             return params, opt_state
         from repro.distrib.shardings import clax_param_rule, make_shardings
@@ -290,6 +296,13 @@ class TrainEngine:
         opt_state = jax.device_put(
             opt_state, make_shardings(self.mesh, opt_state, rule))
         return params, opt_state
+
+    def unplace(self, params, opt_state):
+        """The state in the model's own shapes: scalar tables and their
+        moments back to ``(rows, 1)``, as ``model.init`` gives them (the
+        arguments' arrays are spent)."""
+        return relayout_scalar_tables(self.model, (params, opt_state),
+                                      flat=False, consume=True)
 
     # -- the scanned step ------------------------------------------------------
     def _apply_update(self, params, opt_state, grads, batch):

@@ -134,8 +134,8 @@ class Trainer:
 
     @property
     def final_state(self) -> TrainState:
-        """The state the last :meth:`train` call ended with (its
-        :attr:`engine` is the one that ran it)."""
+        """The state the last :meth:`train` call ended with, in the model's
+        own shapes (its :attr:`engine` is the one that ran it)."""
         return self._final_state
 
     def _rec(self):
@@ -242,8 +242,6 @@ class Trainer:
                 train_loader.load_state_dict(aux["loader"])
             self.log_fn(f"[trainer] resumed at epoch={state.epoch} "
                         f"step={state.global_step}")
-        state.params, state.opt_state = engine.place(state.params,
-                                                     state.opt_state)
         dp = engine.data_parallel_size()
         batch_size = getattr(train_loader, "batch_size", None)
         if dp > 1 and batch_size is not None and batch_size % dp:
@@ -255,6 +253,10 @@ class Trainer:
                 "data-parallel training requires drop_last=True: the "
                 "tail batch generally cannot be split across the "
                 f"{dp}-way data axis (same rule as multi-host streaming)")
+        # Scalar tables train flat and leave in the model's own shapes
+        # (_finish): engine.place and engine.unplace.
+        state.params, state.opt_state = engine.place(state.params,
+                                                     state.opt_state)
 
         preempt = PreemptionHandler() if self.handle_preemption else None
         watchdog = (StepWatchdog(
@@ -395,7 +397,7 @@ class Trainer:
                     epoch_span.__exit__(None, None, None)
                     if profile is not None:
                         profile.close(state.global_step)
-                    self._final_state = state
+                    self._finish(engine, state)
                     return history
                 epoch_span.__exit__(None, None, None)
                 state.epoch += 1
@@ -480,7 +482,7 @@ class Trainer:
                                 f"[trainer] all replicas stopped at epoch "
                                 f"{state.epoch}")
                     break
-            self._final_state = state
+            self._finish(engine, state)
             return history
         finally:
             if profile is not None:
@@ -578,6 +580,11 @@ class Trainer:
                              replicas=replicas)
 
     # -- internals -------------------------------------------------------------------
+    def _finish(self, engine: TrainEngine, state: TrainState) -> None:
+        state.params, state.opt_state = engine.unplace(state.params,
+                                                       state.opt_state)
+        self._final_state = state
+
     def _save(self, state: TrainState, loader, loader_state=None,
               epoch_accum=None, history=None):
         if loader_state is None:
