@@ -21,7 +21,10 @@ One chip, in order:
    1e-5, every loss must be finite, the compiled chunk must hold the Pallas
    ``examination_nll`` kernel (``tpu_custom_call``) and the trace a TPU plane;
 5. the same checks for a CTR-family model (DocumentCTR, ``session_nll``);
-6. serve the trained DBN through repro.serve (ModelRegistry -> warmup ->
+6. train UBM at the same width (one such table and a (K, K) examination
+   table; its loss takes no kernel): every loss finite, and the first
+   batch's loss equal to the model's own loss on that batch to 1e-5;
+7. serve the trained DBN through repro.serve (ModelRegistry -> warmup ->
    ServeEngine, wall clock): every request answered on ``primary``, no model
    error, click probabilities equal to ``predict_clicks`` under ``ref``.
 
@@ -397,6 +400,12 @@ def one_chip(store: str, batch: int, clock: CompileClock) -> None:
     log(f"dctr: compile {clock.lap():.2f}s; losses {losses}")
     check_kernel_ran("dctr", trainer, store, batch, "session_nll")
     check_first_loss("dctr", ctr, losses, device_batch)
+    del trainer
+
+    clock.lap()
+    trainer, ubm, losses, _ = train("ubm", store, batch)
+    log(f"ubm: compile {clock.lap():.2f}s; losses {losses}")
+    check_first_loss("ubm", ubm, losses, device_batch)
     del trainer
 
     clock.lap()

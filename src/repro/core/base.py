@@ -81,9 +81,14 @@ class ClickModel(Module):
             from repro.kernels import session_nll
 
             return session_nll(logits, batch["clicks"], batch["mask"])
+        from repro.kernels.ops import LOSS_SCOPE
+
+        # The unfused path. Its NLL carries the `loss` scope the fused
+        # kernels carry; the lookups run before it, in their own scopes.
         log_probs = self.predict_conditional_clicks(params, batch)
-        nll = log_bce(log_probs, batch["clicks"])
-        return masked_mean(nll, batch["mask"])
+        with jax.named_scope(LOSS_SCOPE):
+            nll = log_bce(log_probs, batch["clicks"])
+            return masked_mean(nll, batch["mask"])
 
     def predict_conditional_logits(self, params, batch: Batch):
         """Raw logits x with log P(C=1 | d, k, c_<k) = log sigmoid(x), or None.

@@ -53,10 +53,23 @@ class UserBrowsingModel(_PartsModel):
     def predict_conditional_clicks(self, params, batch):
         """Eq. 25: log theta_{k,k'} + log gamma_d with observed last click k'."""
         la = self._log_attr(params, batch)
-        exam = self.parts["examination"]
-        k_prime = last_click_positions(batch["clicks"], batch["positions"])
-        logit_e = exam.logit(params["examination"], batch["positions"], k_prime)
-        return log_sigmoid(logit_e) + la
+        return self._log_exam_given_clicks(params, batch) + la
+
+    def _log_exam_given_clicks(self, params, batch):
+        """log theta_{k,k'}: the last-click scan and the (K, K) lookup, in
+        scope ``examination`` inside ``loss``, apart from the attraction
+        table's ``embed``. The scopes name the operations in a trace and
+        nothing else; other callers of ``predict_conditional_clicks``
+        (sampling, conditional serving) carry the same names."""
+        from repro.kernels.ops import LOSS_SCOPE
+
+        # Nested in `loss` because the benchmark's trace reader
+        # (bench/harness/scopes.py) counts an operation under its outermost
+        # scope: the examination and its backward then add to the loss.
+        with jax.named_scope(LOSS_SCOPE), jax.named_scope("examination"):
+            k_prime = last_click_positions(batch["clicks"], batch["positions"])
+            return log_sigmoid(self.parts["examination"].logit(
+                params["examination"], batch["positions"], k_prime))
 
     def predict_clicks(self, params, batch):
         """Eq. 26: marginalize over last-click paths — masked (B, K, K)

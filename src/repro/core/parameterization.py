@@ -313,10 +313,22 @@ class UBMExaminationParameter(Module):
                                   jnp.float32)}
 
     def logit(self, params, k, k_prime):
-        """k: 1-based rank array; k_prime: 0-based last-click rank (0=none)."""
+        """k: 1-based rank array; k_prime: 0-based last-click rank (0=none).
+
+        Computed as a contraction with one-hots of the two indices, which
+        picks the same entries as ``table[k - 1, k_prime]``. The gather's
+        gradient would be a scatter-add of every item into the K^2 cells,
+        each cell hit by thousands of items; the contraction's transpose is
+        a dense product instead (timings in PERF.md). ``HIGHEST`` keeps the
+        float32 entries whole through the matrix unit."""
         k_idx = jnp.clip(k - 1, 0, self.positions - 1)
         kp_idx = jnp.clip(k_prime, 0, self.positions - 1)
-        return params["table"][k_idx, kp_idx]
+        table = params["table"]
+        rows = jax.nn.one_hot(k_idx, self.positions, dtype=table.dtype)
+        cols = jax.nn.one_hot(kp_idx, self.positions, dtype=table.dtype)
+        picked = jnp.einsum("...i,ij->...j", rows, table,
+                            precision=jax.lax.Precision.HIGHEST)
+        return jnp.sum(picked * cols, axis=-1)
 
     def __call__(self, params, batch):  # pragma: no cover - UBM calls .logit
         raise NotImplementedError("UBMExaminationParameter is indexed via .logit")

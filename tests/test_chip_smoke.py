@@ -92,6 +92,7 @@ def test_smoke_phases_pass_at_tiny_width(smoke, capsys):
                  "examination_nll 64x10",
                  "dbn: compiled chunk runs examination_nll",
                  "dctr: compiled chunk runs session_nll",
+                 "ubm: first-batch loss",
                  "sessions/s (smoke figure, not a benchmark)",
                  "serve: click probabilities vs ref"):
         assert line in out

@@ -170,14 +170,14 @@ def _full_table_ops_after(entry, start):
     return found
 
 
-@pytest.mark.parametrize("kind,tables", [("dbn", 2), ("dctr", 1)])
+@pytest.mark.parametrize("kind,tables", [("dbn", 2), ("dctr", 1), ("ubm", 1)])
 def test_baidu_train_step_keeps_scalar_tables_in_one_layout(
         one_chip, monkeypatch, kind, tables):
     """The whole Baidu-width step (model, dense AdamW, donated state) on the
     flat tables ``TrainEngine.place`` gives it reads them where they lie: no
     (rows, 1) buffer, no full-table relayout before the gather, and the
     gradient's scatter-add feeds one AdamW fusion, with no full-table pass
-    between them."""
+    between them. UBM's small examination table takes no scatter."""
     from repro import optim
     from repro.configs import clax_baidu
     from repro.core.parameterization import relayout_scalar_tables
@@ -214,6 +214,14 @@ def test_baidu_train_step_keeps_scalar_tables_in_one_layout(
     for name in scatters:
         after = _full_table_ops_after(entry, name)
         assert len(after) == 1 and after[0][1] == "fusion", (name, after)
+    # one AdamW fusion a table: the parameter and both moments, written once
+    adamw = [name for name, (shape, op, _, _) in entry.items()
+             if op == "fusion" and shape.count(f"f32[{BAIDU_ROWS}]") == 3]
+    assert len(adamw) == tables, adamw
+    # UBM's (K, K) examination table takes its gradient by a contraction,
+    # not by a scatter-add of every item into its K^2 cells
+    assert not re.search(rf"f32\[(100|{BAIDU_K},{BAIDU_K})\]\S* scatter\(",
+                         text)
 
 
 def test_baidu_serving_program_gathers_from_the_table_in_place(one_chip):
